@@ -33,6 +33,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.mergesort import (
     DEFAULT_FANOUT,
     _padded_pow2,
@@ -120,45 +121,49 @@ def merge_topk_batch(x: jax.Array, k: int, block: int = 128,
     b, n = x.shape
     block, nb = candidate_blocks(n, k, block)
     pad = nb * block - n
-    neg = -x.astype(jnp.float32) if jnp.issubdtype(x.dtype, jnp.floating) else -x
-    sentinel = sentinel_max(neg.dtype)
-    keys = jnp.concatenate(
-        [neg, jnp.full((b, pad), sentinel, neg.dtype)], axis=1
-    )
-    idx = jnp.broadcast_to(
-        jnp.arange(nb * block, dtype=jnp.int32), (b, nb * block)
-    )
-    keys, idx = _desc_sort_blocks(
-        keys.reshape(b * nb, block), idx.reshape(b * nb, block)
-    )  # ascending in negated keys
-    # per-block top-k candidates: (b, nb, k)
-    keys = keys.reshape(b, nb, block)[:, :, :k]
-    idx = idx.reshape(b, nb, block)[:, :, :k]
+    with obs.span("repro.topk_block_sort"):
+        neg = (-x.astype(jnp.float32)
+               if jnp.issubdtype(x.dtype, jnp.floating) else -x)
+        sentinel = sentinel_max(neg.dtype)
+        keys = jnp.concatenate(
+            [neg, jnp.full((b, pad), sentinel, neg.dtype)], axis=1
+        )
+        idx = jnp.broadcast_to(
+            jnp.arange(nb * block, dtype=jnp.int32), (b, nb * block)
+        )
+        keys, idx = _desc_sort_blocks(
+            keys.reshape(b * nb, block), idx.reshape(b * nb, block)
+        )  # ascending in negated keys
+        # per-block top-k candidates: (b, nb, k)
+        keys = keys.reshape(b, nb, block)[:, :, :k]
+        idx = idx.reshape(b, nb, block)[:, :, :k]
 
     # Tournament: k-way merge candidate lists, keep top-k each round —
     # one cut for the whole batch per round.
-    r = nb
-    while r > 1:
-        group = min(fanout, r)
-        if r % group:  # pad with sentinel lists to a group multiple
-            extra = group - r % group
-            keys = jnp.concatenate(
-                [keys, jnp.full((b, extra, k), sentinel, keys.dtype)], axis=1
+    with obs.span("repro.topk_tournament"):
+        r = nb
+        while r > 1:
+            group = min(fanout, r)
+            if r % group:  # pad with sentinel lists to a group multiple
+                extra = group - r % group
+                keys = jnp.concatenate(
+                    [keys, jnp.full((b, extra, k), sentinel, keys.dtype)],
+                    axis=1,
+                )
+                idx = jnp.concatenate(
+                    [idx, jnp.zeros((b, extra, k), idx.dtype)], axis=1
+                )
+                r += extra
+            mk, mi = merge_runs_ranked(
+                keys.reshape(b * (r // group), group, k),
+                idx.reshape(b * (r // group), group, k),
             )
-            idx = jnp.concatenate(
-                [idx, jnp.zeros((b, extra, k), idx.dtype)], axis=1
-            )
-            r += extra
-        mk, mi = merge_runs_ranked(
-            keys.reshape(b * (r // group), group, k),
-            idx.reshape(b * (r // group), group, k),
-        )
-        keys = mk.reshape(b, r // group, group * k)[:, :, :k]
-        idx = mi.reshape(b, r // group, group * k)[:, :, :k]
-        r //= group
+            keys = mk.reshape(b, r // group, group * k)[:, :, :k]
+            idx = mi.reshape(b, r // group, group * k)[:, :, :k]
+            r //= group
 
-    vals = -keys[:, 0]
-    return vals.astype(x.dtype), idx[:, 0]
+        vals = -keys[:, 0]
+        return vals.astype(x.dtype), idx[:, 0]
 
 
 @partial(jax.jit, static_argnames=("k", "block", "fanout"))

@@ -48,6 +48,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.core import engine
 from repro.core.corank import co_rank_batch
 from repro.core.kway import co_rank_kway_batch
@@ -232,14 +233,15 @@ def _slabs(x: jax.Array, s: int) -> jax.Array:
 SMEM_CUTS = 1 << 16
 
 
-def _merge_call(cb, sources, *, k, s, g, nblocks, interpret, semantics):
+def _merge_call(cb, sources, *, k, s, g, nblocks, interpret, semantics,
+                name):
     """Merge ``g`` tiles, ``SMEM_CUTS // k - 1`` tiles per call."""
     per_call = max(1, SMEM_CUTS // k - 1)
     outs = [
         _merge_tiles(
             cb[c0 * k : (min(c0 + per_call, g) + 1) * k], sources,
             k=k, s=s, g=min(per_call, g - c0), nblocks=nblocks,
-            interpret=interpret, semantics=semantics,
+            interpret=interpret, semantics=semantics, name=name,
         )
         for c0 in range(0, g, per_call)
     ]
@@ -248,12 +250,15 @@ def _merge_call(cb, sources, *, k, s, g, nblocks, interpret, semantics):
     return [jnp.concatenate(parts, axis=0) for parts in zip(*outs)]
 
 
-def _merge_tiles(cb, sources, *, k, s, g, nblocks, interpret, semantics):
+def _merge_tiles(cb, sources, *, k, s, g, nblocks, interpret, semantics,
+                 name):
     """One ``pallas_call`` over ``g`` tiles.
 
     ``sources``: per run ``q`` a ``(slabs, base)`` pair — the run's
     blocks sit at ``slabs[base : base + nblocks[q]]``; a second list of
-    pairs, if present, is the payload with the same placement.
+    pairs, if present, is the payload with the same placement.  ``name``
+    names the custom call (``%<name>.N``), whatever scope it runs in: a
+    device trace finds the kernel by it.
     """
     key_sources, val_sources = sources
     block = (None,) + _tile_layout(s)
@@ -293,6 +298,7 @@ def _merge_tiles(cb, sources, *, k, s, g, nblocks, interpret, semantics):
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name=name,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(semantics,),
         ),
@@ -351,25 +357,31 @@ def merge_pallas(
     dtype = jnp.result_type(a, b)
     s = tile
 
-    # Logical padding to S-multiples (sentinels merge stably to the tail).
-    m2 = -(-max(m, 1) // s) * s
-    n2 = -(-max(n, 1) // s) * s
-    a_log = _pad_to(a.astype(dtype), m2)
-    b_log = _pad_to(b.astype(dtype), n2)
-    g = (m2 + n2) // s
+    with obs.span("repro.slab_relayout"):
+        # Logical padding to S-multiples (sentinels merge stably to the tail).
+        m2 = -(-max(m, 1) // s) * s
+        n2 = -(-max(n, 1) // s) * s
+        a_log = _pad_to(a.astype(dtype), m2)
+        b_log = _pad_to(b.astype(dtype), n2)
+        g = (m2 + n2) // s
 
-    # Phase 1: co-rank the G+1 tile boundaries (the paper's Algorithm 1).
-    bounds = jnp.arange(g + 1, dtype=jnp.int32) * s
-    cr = co_rank_batch(bounds, a_log, b_log)
-    cb = jnp.stack([cr.j, cr.k], axis=1).reshape(-1)
+    with obs.span("repro.corank"):
+        # Phase 1: co-rank the G+1 tile boundaries (the paper's Algorithm 1).
+        bounds = jnp.arange(g + 1, dtype=jnp.int32) * s
+        cr = co_rank_batch(bounds, a_log, b_log)
+        cb = jnp.stack([cr.j, cr.k], axis=1).reshape(-1)
 
-    sources = ([(_slabs(_wide(a_log), s), 0), (_slabs(_wide(b_log), s), 0)],
-               None)
-    (out,) = _merge_call(
-        cb, sources, k=2, s=s, g=g, nblocks=(m2 // s, n2 // s),
-        interpret=interpret, semantics=dimension_semantics,
-    )
-    return out.reshape(-1)[: m + n].astype(dtype)
+    with obs.span("repro.slab_relayout"):
+        sources = ([(_slabs(_wide(a_log), s), 0),
+                    (_slabs(_wide(b_log), s), 0)], None)
+    with obs.span("repro.tile_merge"):
+        (out,) = _merge_call(
+            cb, sources, k=2, s=s, g=g, nblocks=(m2 // s, n2 // s),
+            interpret=interpret, semantics=dimension_semantics,
+            name="merge_pallas",
+        )
+    with obs.span("repro.slab_relayout"):
+        return out.reshape(-1)[: m + n].astype(dtype)
 
 
 @functools.partial(
@@ -419,35 +431,41 @@ def merge_kway_pallas(
     dtype = runs.dtype
     s = tile or kway_tile(k, w)
 
-    w2 = -(-max(w, 1) // s) * s
-    runs_log = _pad_to(runs, w2)
-    if lengths is None and w2 != w:
-        # Co-rank against the real width, so the tail padding never
-        # displaces a real dtype-max key (or its payload).
-        lengths = jnp.full((k,), w, jnp.int32)
+    with obs.span("repro.slab_relayout"):
+        w2 = -(-max(w, 1) // s) * s
+        runs_log = _pad_to(runs, w2)
     g = k * w2 // s
     nb = w2 // s
 
-    # Phase 1: multi-way co-rank of the G+1 tile boundaries (ragged rows
-    # clamp at their real lengths, exactly as in core.kway).
-    lengths = None if lengths is None else jnp.asarray(lengths, jnp.int32)
-    bounds = jnp.arange(g + 1, dtype=jnp.int32) * s
-    cb = co_rank_kway_batch(bounds, runs_log, lengths).reshape(-1)
+    with obs.span("repro.corank"):
+        if lengths is None and w2 != w:
+            # Co-rank against the real width, so the tail padding never
+            # displaces a real dtype-max key (or its payload).
+            lengths = jnp.full((k,), w, jnp.int32)
+        # Phase 1: multi-way co-rank of the G+1 tile boundaries (ragged
+        # rows clamp at their real lengths, exactly as in core.kway).
+        lengths = None if lengths is None else jnp.asarray(lengths, jnp.int32)
+        bounds = jnp.arange(g + 1, dtype=jnp.int32) * s
+        cb = co_rank_kway_batch(bounds, runs_log, lengths).reshape(-1)
 
-    key_slabs = _slabs(_wide(runs_log), s)
-    val_sources = None
-    if vals is not None:
-        vals_log = jnp.concatenate(
-            [vals, jnp.zeros((k, w2 - w), vals.dtype)], axis=1
+    with obs.span("repro.slab_relayout"):
+        key_slabs = _slabs(_wide(runs_log), s)
+        val_sources = None
+        if vals is not None:
+            vals_log = jnp.concatenate(
+                [vals, jnp.zeros((k, w2 - w), vals.dtype)], axis=1
+            )
+            val_slabs = _slabs(_wide(vals_log), s)
+            val_sources = [(val_slabs, q * nb) for q in range(k)]
+        sources = ([(key_slabs, q * nb) for q in range(k)], val_sources)
+    with obs.span("repro.tile_merge"):
+        out = _merge_call(
+            cb, sources, k=k, s=s, g=g, nblocks=(nb,) * k,
+            interpret=interpret, semantics=dimension_semantics,
+            name="merge_kway_pallas",
         )
-        val_slabs = _slabs(_wide(vals_log), s)
-        val_sources = [(val_slabs, q * nb) for q in range(k)]
-    sources = ([(key_slabs, q * nb) for q in range(k)], val_sources)
-    out = _merge_call(
-        cb, sources, k=k, s=s, g=g, nblocks=(nb,) * k,
-        interpret=interpret, semantics=dimension_semantics,
-    )
-    keys = out[0].reshape(-1)[: k * w].astype(dtype)
-    if vals is None:
-        return keys
-    return keys, out[1].reshape(-1)[: k * w].astype(vals.dtype)
+    with obs.span("repro.slab_relayout"):
+        keys = out[0].reshape(-1)[: k * w].astype(dtype)
+        if vals is None:
+            return keys
+        return keys, out[1].reshape(-1)[: k * w].astype(vals.dtype)

@@ -9,6 +9,12 @@ Where ``$JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and the
 cache goes there; no code sets another path.  Otherwise the cache lives
 at ``<checkout>/.jax_cache`` — a fixed path, never a temporary, pid- or
 time-based one, because a directory that moves never hits.
+
+Entries are keyed on the programs' op metadata too.  JAX leaves it out
+of the key by default, but a cached executable keeps the metadata it
+was compiled with: the ``repro.*`` scopes (``obs.span``) that a profile
+names each device op by would otherwise be those of whatever version of
+the code filled the entry first.
 """
 
 from __future__ import annotations
@@ -30,4 +36,5 @@ def enable_compile_cache() -> str:
     if not path:
         path = str(DEFAULT_CACHE_DIR)
         jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn_mod
 from repro.models import layers as L
@@ -521,31 +522,37 @@ def _decode_gqa_ragged(cfg, params, cache, x, cos, sin, positions, lengths):
     def make_body(moe_layer):
         def body(xx, inp):
             lp, kl, vl = inp
-            h = L.rmsnorm(lp["ln1"], xx)
-            q, k, v = attn_mod.qkv_project(
-                lp["attn"], h, cos, sin, positions, qk_norm=cfg.qk_norm
-            )
-            # per-slot scatter: slot b's token lands at its own position
-            kl = kl.at[rows, lengths].set(k[:, 0])
-            vl = vl.at[rows, lengths].set(v[:, 0])
-            o = attn_mod.decode_attention(q, kl, vl, lengths + 1)
-            xx = xx + attn_mod.attention_output(lp["attn"], o, xx.dtype)
-            xx = _ffn_block(cfg, lp, xx, moe_layer=moe_layer)
+            with obs.span("repro.attention"):
+                h = L.rmsnorm(lp["ln1"], xx)
+                q, k, v = attn_mod.qkv_project(
+                    lp["attn"], h, cos, sin, positions, qk_norm=cfg.qk_norm
+                )
+                # per-slot scatter: slot b's token lands at its own position
+                kl = kl.at[rows, lengths].set(k[:, 0])
+                vl = vl.at[rows, lengths].set(v[:, 0])
+                o = attn_mod.decode_attention(q, kl, vl, lengths + 1)
+                xx = xx + attn_mod.attention_output(lp["attn"], o, xx.dtype)
+            with obs.span("repro.ffn"):
+                xx = _ffn_block(cfg, lp, xx, moe_layer=moe_layer)
             return xx, (kl, vl)
 
         return body
 
     layers = params["layers"]
-    if cfg.moe and cfg.first_k_dense:
-        nd = cfg.first_k_dense
-        x, (kd, vd) = lax.scan(
-            make_body(False), x, (params["dense_layers"], kc[:nd], vc[:nd])
-        )
-        x, (km, vm) = lax.scan(make_body(cfg.moe), x, (layers, kc[nd:], vc[nd:]))
-        k_new = jnp.concatenate([kd, km], axis=0)
-        v_new = jnp.concatenate([vd, vm], axis=0)
-    else:
-        x, (k_new, v_new) = lax.scan(make_body(cfg.moe), x, (layers, kc, vc))
+    with obs.span("repro.decoder_layers"):
+        if cfg.moe and cfg.first_k_dense:
+            nd = cfg.first_k_dense
+            x, (kd, vd) = lax.scan(
+                make_body(False), x,
+                (params["dense_layers"], kc[:nd], vc[:nd]),
+            )
+            x, (km, vm) = lax.scan(
+                make_body(cfg.moe), x, (layers, kc[nd:], vc[nd:]))
+            k_new = jnp.concatenate([kd, km], axis=0)
+            v_new = jnp.concatenate([vd, vm], axis=0)
+        else:
+            x, (k_new, v_new) = lax.scan(
+                make_body(cfg.moe), x, (layers, kc, vc))
     return x, Cache("gqa", (k_new, v_new), lengths + 1)
 
 

@@ -1,11 +1,22 @@
 """Runtime telemetry: jit-safe metrics, invariant counters, trace spans.
 
-Enable with ``obs.enable(metrics_dir=...)`` (JSONL under that directory)
-or ``obs.enable(sink=...)`` / ``obs.capture()`` (in-memory, tests).
-While disabled — the default — every record point is a trace-time no-op:
-instrumented functions compile to identical HLO, modulo debug metadata
-(asserted in ``tests/test_obs.py``), so the hot paths carry their probes
-for free.
+Scopes and host spans are always on; metrics are opt-in.
+
+* Scopes (``obs.span``, ``jax.named_scope``) name the ops of a phase in
+  their ``op_name`` metadata, and host spans (``obs.host_span``,
+  ``jax.profiler.TraceAnnotation``) mark a phase of host code on the
+  profiler's clock, so every profile of every run shows them.  A scope is
+  metadata only: with metadata stripped, the compiled program is the same
+  with or without it (asserted in ``tests/test_obs.py``).  A host span
+  costs about a microsecond when no profiler session records.
+* Metrics (``counter`` / ``gauge`` / ``histogram``) are on after
+  ``obs.enable(metrics_dir=...)`` (JSONL under that directory) or
+  ``obs.enable(sink=...)`` / ``obs.capture()`` (in-memory, tests).  While
+  disabled — the default — every record point is a trace-time no-op:
+  instrumented functions compile to identical HLO, modulo debug metadata,
+  so the hot paths carry their probes for free.  Enabled, a record made
+  while tracing rides a ``jax.debug.callback``; one made in host code
+  goes straight to the sink.
 
 JSONL schema (one object per line)::
 
@@ -110,9 +121,6 @@ Metrics catalog — every record point woven through the hot paths:
                              value <= capacity on every step.
 ``serve.slots_recycled``     counter: slot free() calls (recycling is a
                              length reset, never a KV zeroing pass).
-``serve.step_latency``       gauge: wall-clock microseconds of one
-                             engine step (ragged decode + batched
-                             sample, blocking); labels ``batch``.
 ``serve.topk_merge_rounds``  gauge: merge_kway cuts per batched top-k
                              call — a function of vocab/fanout geometry
                              only, NEVER batch size (the one-merge-
@@ -136,10 +144,49 @@ Metrics catalog — every record point woven through the hot paths:
 ``obs.profile_started`` / ``obs.profile_stopped`` events: profiler
                              trace-dump window (``--profile-steps``).
 
-Spans: subsystem boundaries (``sharded_sort``, ``exchange_block``,
-``dropless_moe_ffn``, ``merge_kway``, kernel dispatch) sit inside
-``obs.span("repro.<name>")`` — ``jax.named_scope`` groups their ops in
-profiler views; launcher loops use ``step_span`` / ``host_span``.
+Scopes (``obs.span``; stable ``repro.<name>``, read from a device trace's
+``tf_op`` stat by ``bench/scopes.py``):
+
+== Subsystem boundaries ==
+``repro.stable_merge`` / ``repro.stable_merge_kway`` /
+``repro.merge_window`` / ``repro.stable_sort`` (kernel dispatch),
+``repro.merge_kway``, ``repro.sharded_sort``, ``repro.local_sort``,
+``repro.sharded_merge_kway.<strategy>``, ``repro.splitters``,
+``repro.local_merge``, ``repro.exchange_block``,
+``repro.dropless_moe_ffn``, ``repro.dropless_dispatch``,
+``repro.moe_grouped_gemm``, ``repro.dropless_combine``.
+
+== Phases of the hot paths ==
+``repro.corank``             Pallas merges (``merge_pallas``,
+                             ``merge_kway_pallas``): phase 1, the
+                             co-rank of every tile boundary.
+``repro.slab_relayout``      ... padding to tiles, widening, the slab
+                             layout and payload concatenation, and the
+                             reshape, slice and narrowing of the result.
+``repro.tile_merge``         ... the Pallas tile-merge calls.
+``repro.topk_block_sort``    ``merge_topk_batch``: negate, pad, the
+                             in-block stable sort, per-block top-k.
+``repro.topk_tournament``    ... the candidate-merge rounds.
+``repro.topk_draw``          ``sample_topk_batched`` /
+                             ``sample_topp_batched``: softmax, the
+                             nucleus cut, the categorical draw.
+``repro.decoder_layers``     ragged decode step: the layer scan; what
+                             lies under it but under neither of the next
+                             two is the scan's own per-layer slicing and
+                             stacking of the KV cache.
+``repro.attention``          ... in the scan body: ln1, QKV, the KV
+                             write, attention, output projection and
+                             residual.
+``repro.ffn``                ... in the scan body: the FFN block.
+
+Host spans (``obs.host_span``): ``repro.external_sort``,
+``serve.prefill`` (lock-step launcher), and ``DecodeEngine.step``'s
+``serve.step`` around ``serve.admit`` (admission, slot allocation),
+``serve.build_inputs`` (input arrays and their transfer),
+``serve.dispatch`` (decode step, cache swap, keys, sampler),
+``serve.fetch_tokens`` (the blocking wait for sampled tokens) and
+``serve.retire`` (per-slot bookkeeping, completion, slot free).
+Launcher loops also mark steps with ``step_span`` while obs is enabled.
 """
 
 from repro.obs.registry import (

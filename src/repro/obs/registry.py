@@ -5,9 +5,11 @@ disabled record point contributes zero operations to the jaxpr — the
 compiled HLO of an instrumented function is identical to the
 un-instrumented program, modulo debug metadata (asserted in
 ``tests/test_obs.py``).  When
-enabled, the traced value rides a ``jax.debug.callback`` to the host,
+enabled, a traced value rides a ``jax.debug.callback`` to the host,
 where it is normalised (numpy -> plain Python) and appended to the
-active sink as one JSONL-shaped record.
+active sink as one JSONL-shaped record; a host value (a record point in
+host code, such as the serving engine's) is appended directly, with no
+callback and no device round trip.
 
 Because enablement is baked in at trace time, toggling it must not let
 stale compilations leak: :func:`enable` / :func:`disable` call
@@ -174,17 +176,27 @@ def totals() -> dict[str, float]:
 
 
 def record(name: str, value, *, kind: str = "gauge", **labels) -> None:
-    """The one record point: no-op when disabled, callback when enabled.
+    """The one record point: no-op when disabled; when enabled, a record
+    made while tracing rides a callback, one made in host code goes
+    straight to the sink.
 
-    ``value`` may be a traced scalar or array.  ``labels`` are attached
-    to the record; plain Python values stay host-side, ``jax.Array`` /
-    traced values are forwarded through the callback (e.g.
-    ``device=lax.axis_index("x")``).
+    ``value`` may be a traced scalar or array, or a host number or array.
+    ``labels`` are attached to the record; plain Python values stay
+    host-side, traced values are forwarded through the callback (e.g.
+    ``device=lax.axis_index("x")``).  Inside a trace the callback fires
+    on every execution, whatever the value; outside one nothing is
+    bound, so a record point in host code costs no device round trip.
     """
     if not _STATE.enabled:
         return
     import jax
     import jax.numpy as jnp
+
+    if jax.core.trace_ctx.is_top_level():  # host code: nothing traced
+        _emit(name, kind, np.asarray(value), {
+            k: _normalise(np.asarray(v)) if isinstance(v, jax.Array) else v
+            for k, v in labels.items()})
+        return
 
     static = {}
     traced_keys: list[str] = []

@@ -29,7 +29,6 @@ pins this.
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -134,65 +133,73 @@ class DecodeEngine:
 
         Returns ``{"admitted": [rids], "sampled": {rid: token},
         "completed": [rids], "active": int}`` for the caller's loop.
+
+        Each phase runs inside a host span (``serve.step`` around
+        ``serve.admit``, ``serve.build_inputs``, ``serve.dispatch``,
+        ``serve.fetch_tokens``, ``serve.retire``), so a profiler trace
+        puts each device-idle gap down to the phase the chip waited on.
         """
-        sched, pool = self.scheduler, self.pool
-        t0 = time.perf_counter()
+        with obs.host_span("serve.step"):
+            sched, pool = self.scheduler, self.pool
 
-        n_free = min(pool.free_slots, sched.queued)
-        placed = sched.admit([pool.alloc() for _ in range(n_free)])
-        if obs.enabled():
-            obs.gauge("serve.active_slots", sched.active_slots,
-                      capacity=pool.capacity)
-        occupied = sched.occupied()
-        if not occupied:
-            return {"admitted": [], "sampled": {}, "completed": [],
-                    "active": 0}
+            with obs.host_span("serve.admit"):
+                n_free = min(pool.free_slots, sched.queued)
+                placed = sched.admit([pool.alloc() for _ in range(n_free)])
+                if obs.enabled():
+                    obs.gauge("serve.active_slots", sched.active_slots,
+                              capacity=pool.capacity)
+                occupied = sched.occupied()
+            if not occupied:
+                return {"admitted": [], "sampled": {}, "completed": [],
+                        "active": 0}
 
-        b = pool.capacity
-        tokens = np.zeros((b, 1), np.int32)
-        active = np.zeros((b,), bool)
-        due = np.zeros((b,), bool)
-        rids = np.zeros((b,), np.uint32)
-        gens = np.zeros((b,), np.uint32)
-        for slot, st in occupied:
-            tokens[slot, 0] = st.next_feed
-            active[slot] = True
-            due[slot] = st.samples_this_step
-            rids[slot] = st.request.rid
-            gens[slot] = st.generated
+            with obs.host_span("serve.build_inputs"):
+                b = pool.capacity
+                tokens = np.zeros((b, 1), np.int32)
+                active = np.zeros((b,), bool)
+                due = np.zeros((b,), bool)
+                rids = np.zeros((b,), np.uint32)
+                gens = np.zeros((b,), np.uint32)
+                for slot, st in occupied:
+                    tokens[slot, 0] = st.next_feed
+                    active[slot] = True
+                    due[slot] = st.samples_this_step
+                    rids[slot] = st.request.rid
+                    gens[slot] = st.generated
+                tokens, active = jnp.asarray(tokens), jnp.asarray(active)
 
-        logits, cache = self._step_fn(
-            self.params, pool.cache, jnp.asarray(tokens), jnp.asarray(active)
-        )
-        pool.set_cache(cache.data, cache.length)
-        keys = _sample_keys(
-            self._base_key, jnp.asarray(rids), jnp.asarray(gens)
-        )
-        nxt = np.asarray(self._sample(keys, logits))  # blocks: step done
+            with obs.host_span("serve.dispatch"):
+                logits, cache = self._step_fn(
+                    self.params, pool.cache, tokens, active)
+                pool.set_cache(cache.data, cache.length)
+                # the keys' inputs go over while the device runs the step
+                keys = _sample_keys(
+                    self._base_key, jnp.asarray(rids), jnp.asarray(gens)
+                )
+                nxt = self._sample(keys, logits)
 
-        sampled: dict[int, int] = {}
-        completed: list[int] = []
-        for slot, st in occupied:
-            if st.fed < st.request.prompt.size:
-                st.fed += 1
-            if due[slot]:
-                tok = int(nxt[slot])
-                st.tokens.append(tok)
-                st.generated += 1
-                sampled[st.request.rid] = tok
-            if st.done:
-                req = sched.complete(slot)
-                pool.free(slot)
-                self.results[req.rid] = list(st.tokens)
-                completed.append(req.rid)
+            with obs.host_span("serve.fetch_tokens"):
+                nxt = np.asarray(nxt)  # blocks: step done
 
-        self.steps += 1
-        if obs.enabled():
-            obs.gauge("serve.step_latency",
-                      (time.perf_counter() - t0) * 1e6,
-                      batch=len(occupied), unit="us")
-        return {"admitted": [r.rid for _, r in placed], "sampled": sampled,
-                "completed": completed, "active": len(occupied)}
+            with obs.host_span("serve.retire"):
+                sampled: dict[int, int] = {}
+                completed: list[int] = []
+                for slot, st in occupied:
+                    if st.fed < st.request.prompt.size:
+                        st.fed += 1
+                    if due[slot]:
+                        tok = int(nxt[slot])
+                        st.tokens.append(tok)
+                        st.generated += 1
+                        sampled[st.request.rid] = tok
+                    if st.done:
+                        req = sched.complete(slot)
+                        pool.free(slot)
+                        self.results[req.rid] = list(st.tokens)
+                        completed.append(req.rid)
+                self.steps += 1
+            return {"admitted": [r.rid for _, r in placed], "sampled": sampled,
+                    "completed": completed, "active": len(occupied)}
 
     # -- drive -------------------------------------------------------------
 

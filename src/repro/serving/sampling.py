@@ -145,11 +145,13 @@ def sample_topk_batched(keys, logits, k: int = 50,
     per-row key.
     """
     vals, idx = batched_topk(logits, k, fanout=fanout)
-    probs = jax.nn.softmax(vals.astype(jnp.float32) / temperature, axis=-1)
-    choice = jax.vmap(
-        lambda kk, pp: jax.random.categorical(kk, jnp.log(pp + 1e-20))
-    )(keys, probs)
-    return jnp.take_along_axis(idx, choice[:, None], axis=1)[:, 0]
+    with obs.span("repro.topk_draw"):
+        probs = jax.nn.softmax(
+            vals.astype(jnp.float32) / temperature, axis=-1)
+        choice = jax.vmap(
+            lambda kk, pp: jax.random.categorical(kk, jnp.log(pp + 1e-20))
+        )(keys, probs)
+        return jnp.take_along_axis(idx, choice[:, None], axis=1)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "fanout"))
@@ -162,14 +164,16 @@ def sample_topp_batched(keys, logits, p: float = 0.9, k: int = 256,
     ``cum - probs < p`` prefix because that run is nondecreasing.
     """
     vals, idx = batched_topk(logits, k, fanout=fanout)
-    probs = jax.nn.softmax(vals.astype(jnp.float32) / temperature, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    n_keep = jax.vmap(
-        lambda row: engine.value_cut_counts(row, jnp.float32(p))
-    )(cum - probs)
-    keep = jnp.arange(k, dtype=jnp.int32)[None, :] < n_keep[:, None]
-    probs = jnp.where(keep, probs, 0.0)
-    choice = jax.vmap(
-        lambda kk, pp: jax.random.categorical(kk, jnp.log(pp + 1e-20))
-    )(keys, probs)
-    return jnp.take_along_axis(idx, choice[:, None], axis=1)[:, 0]
+    with obs.span("repro.topk_draw"):
+        probs = jax.nn.softmax(
+            vals.astype(jnp.float32) / temperature, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        n_keep = jax.vmap(
+            lambda row: engine.value_cut_counts(row, jnp.float32(p))
+        )(cum - probs)
+        keep = jnp.arange(k, dtype=jnp.int32)[None, :] < n_keep[:, None]
+        probs = jnp.where(keep, probs, 0.0)
+        choice = jax.vmap(
+            lambda kk, pp: jax.random.categorical(kk, jnp.log(pp + 1e-20))
+        )(keys, probs)
+        return jnp.take_along_axis(idx, choice[:, None], axis=1)[:, 0]

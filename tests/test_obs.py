@@ -92,6 +92,168 @@ def test_disabled_record_adds_no_jaxpr_ops():
 
 
 # ---------------------------------------------------------------------------
+# always-on scopes (metadata only) and host spans
+# ---------------------------------------------------------------------------
+
+
+def _sds(shape, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _hlo_merge_kway():
+    from repro.kernels.merge import merge_kway_pallas
+
+    runs = _sds((4, 200))  # padded to 2 tiles a run, sliced back
+    return merge_kway_pallas.lower(
+        runs, runs, lengths=_sds((4,)), tile=128, interpret=True
+    ).compile().as_text()
+
+
+def _hlo_merge_pair():
+    from repro.kernels.merge import merge_pallas
+
+    return merge_pallas.lower(
+        _sds((300,)), _sds((200,)), tile=128, interpret=True
+    ).compile().as_text()
+
+
+def _hlo_sample_topk():
+    from repro.serving.sampling import sample_topk_batched
+
+    keys = jax.random.split(jax.random.key(0), 3)
+    return sample_topk_batched.lower(
+        keys, _sds((3, 1000), jnp.float32), k=8, fanout=4
+    ).compile().as_text()
+
+
+def _hlo_ragged_step():
+    from repro.configs.registry import ARCHS, smoke_config
+    from repro.models.transformer import init_params
+    from repro.serving.engine import _ragged_step_fn
+    from repro.serving.kv_pool import KVPool
+
+    cfg = smoke_config(ARCHS["qwen3-0.6b"])
+    params, _ = init_params(cfg, jax.random.key(0))
+    cache = KVPool(cfg, 2, 32, jnp.bfloat16).cache
+    return _ragged_step_fn(cfg).lower(
+        params, cache, _sds((2, 1)), _sds((2,), jnp.bool_)
+    ).compile().as_text()
+
+
+_SCOPED = {
+    "merge_kway_pallas": (
+        _hlo_merge_kway,
+        ("repro.corank", "repro.slab_relayout", "repro.tile_merge")),
+    "merge_pallas": (
+        _hlo_merge_pair,
+        ("repro.corank", "repro.slab_relayout", "repro.tile_merge")),
+    "sample_topk_batched": (
+        _hlo_sample_topk,
+        ("repro.topk_block_sort", "repro.topk_tournament",
+         "repro.topk_draw")),
+    "ragged_step": (
+        _hlo_ragged_step,
+        ("repro.decoder_layers", "repro.attention", "repro.ffn")),
+}
+
+
+def _scope_paths(hlo: str) -> list[list[str]]:
+    return [p.split("/") for p in re.findall(r'op_name="([^"]*)"', hlo)]
+
+
+@pytest.mark.parametrize("fn", sorted(_SCOPED))
+def test_scopes_in_compiled_hlo_while_disabled(fn):
+    """With obs off, every op of a scoped region carries its stable
+    ``repro.*`` scope in its ``op_name`` path."""
+    assert not obs.enabled()
+    lower, scopes = _SCOPED[fn]
+    paths = _scope_paths(lower())
+    for scope in scopes:
+        assert any(scope in p for p in paths), (fn, scope)
+    if fn == "ragged_step":  # the layer scopes sit inside the scan's
+        for scope in ("repro.attention", "repro.ffn"):
+            assert any("repro.decoder_layers" in p and scope in p
+                       for p in paths), scope
+
+
+@pytest.mark.parametrize("fn", ["merge_kway_pallas", "ragged_step"])
+def test_scopes_are_metadata_only(fn, monkeypatch):
+    """The compiled program with scopes equals the one with ``obs.span``
+    a null context, once metadata is stripped."""
+    import contextlib
+
+    lower, scopes = _SCOPED[fn]
+    jax.clear_caches()
+    scoped = lower()
+    monkeypatch.setattr(obs, "span", lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+    plain = lower()
+    jax.clear_caches()
+    assert not any(scopes[0] in p for p in _scope_paths(plain))
+    assert any(scopes[0] in p for p in _scope_paths(scoped))
+    assert _canon(scoped) == _canon(plain)
+
+
+def test_engine_step_opens_its_host_spans(monkeypatch):
+    """One engine step opens the six ``serve.*`` spans, the five phases
+    in order inside ``serve.step``."""
+    from repro.configs.registry import ARCHS, smoke_config
+    from repro.models.transformer import init_params
+    from repro.serving import DecodeEngine, Request
+
+    events = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            events.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            events.append(("exit", self.name))
+
+    cfg = smoke_config(ARCHS["qwen3-0.6b"])
+    params, _ = init_params(cfg, jax.random.key(0))
+    engine = DecodeEngine(cfg, params, max_len=16, max_batch=2,
+                          queue_depth=2, sampler="topk", top_k=4)
+    engine.submit(Request(0, np.asarray([3, 5], np.int32), 2))
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    engine.step()
+    phases = ["serve.admit", "serve.build_inputs", "serve.dispatch",
+              "serve.fetch_tokens", "serve.retire"]
+    want = [("enter", "serve.step")]
+    for name in phases:
+        want += [("enter", name), ("exit", name)]
+    want.append(("exit", "serve.step"))
+    assert [e for e in events if e[1].startswith("serve.")] == want
+
+
+def test_host_record_binds_no_callback(monkeypatch):
+    """A record point in host code goes straight to the sink, with the
+    record a traced one makes; inside a trace the callback stays."""
+    with obs.capture() as recs:
+        jax.jit(lambda x: (obs.gauge("t.host", 7, capacity=4), x)[1])(1)
+        obs.flush()
+    (traced,) = [r for r in recs if r["metric"] == "t.host"]
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("host record bound a debug callback")
+
+    monkeypatch.setattr(jax.debug, "callback", refuse)
+    with obs.capture() as recs:
+        obs.gauge("t.host", 7, capacity=4)
+        obs.counter("t.host_c", np.int64(3))
+        obs.flush()
+        assert obs.totals()["t.host_c"] == 3
+    (host,) = [r for r in recs if r["metric"] == "t.host"]
+    assert {k: v for k, v in host.items() if k != "ts"} == {
+        k: v for k, v in traced.items() if k != "ts"}
+    assert host == {"ts": host["ts"], "metric": "t.host", "kind": "gauge",
+                    "value": 7, "labels": {"capacity": 4}}
+
+
+# ---------------------------------------------------------------------------
 # registry / sink behaviour
 # ---------------------------------------------------------------------------
 
