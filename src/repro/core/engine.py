@@ -8,7 +8,7 @@ the runs:
 tier                    probe / reads                          loop
 ======================  =====================================  ==========
 ``core.corank``         local array indexing                   dynamic ``lax.while_loop`` (Prop.-1 counted)
-``core.kway``           vectorised ``searchsorted`` (k, w)     static ``lax.fori_loop``
+``core.kway``           row searches of b key midpoints        static ``lax.fori_loop`` over the key bits
 ``distributed``         ``all_gather`` + masked ``psum``       static ``lax.fori_loop`` (lock-step collective rounds)
 ``external.planner``    ``np.searchsorted`` over mmap'd runs   plain Python loop
 ``kernels.merge``       tier-1 cuts, scalar-prefetched         rotate + bitonic :func:`precedes` network per tile
@@ -24,7 +24,9 @@ must agree on bit-for-bit:
   decision through :func:`take_first` / :func:`kfinger_better` /
   :func:`precedes`;
 * the **lock-step k-way bisection loop** (:func:`co_rank_search`),
-  parameterized by a :class:`Probe`;
+  parameterized by a :class:`Probe`, and its **value-space** form over
+  keys mapped to int32 (:func:`co_rank_value_search`, which ends in the
+  run-order split of ties, :func:`tie_split_cuts`);
 * the **pairwise Algorithm 1** double-ended search
   (:func:`co_rank_pairwise`), parameterized by two read functions;
 * the **padding/length clamp** (padded tail positions are never
@@ -50,6 +52,11 @@ Paper mapping
   on a local array or over collectives).  The k-way form replaces the
   double-ended search with one monotone bisection per run
   (``j_r(i) = |{t : rank(r, t) < i}|``): :func:`co_rank_search`.
+  On a device the same cut is found by bisecting the *key* of the
+  i-th merged element instead (one search per run a round, not one
+  merged rank per run): :func:`co_rank_value_search`; its ties are
+  split across the runs in run order — Lemma 1's tie-break once more
+  (:func:`tie_split_cuts`).
 * **Proposition 1** — the iteration bound
   ``ceil(log2 min(m, n)) + 1``: :func:`prop1_bound` checks the dynamic
   while-loop count; :func:`kway_round_bound` is the static lock-step
@@ -102,6 +109,7 @@ __all__ = [
     "kfinger_better",
     "lemma1_counts",
     "value_cut_counts",
+    "tie_split_cuts",
     "prop1_bound",
     "kway_round_bound",
     "pairwise_lockstep_rounds",
@@ -109,6 +117,7 @@ __all__ = [
     "run_host",
     "Probe",
     "co_rank_search",
+    "co_rank_value_search",
     "co_rank_pairwise",
 ]
 
@@ -221,6 +230,23 @@ def value_cut_counts(run, boundary_values, length=None, xp=jnp):
     if length is not None:
         local = xp.minimum(local, length)
     return local
+
+
+def tie_split_cuts(i, le, lt):
+    """Cut of rank ``i`` once the key ``v`` of its merged element is known.
+
+    ``le`` / ``lt`` are each run's counts of its real elements ``<= v`` /
+    ``< v`` (Lemma 1's two sides, clamped to the run's length), shaped
+    ``(k, ...)`` with the runs first.  Every element below ``v`` sorts
+    before rank ``i``; the ``i - L`` places left (``L`` the elements
+    below ``v``) go to the ties of ``v`` in run order: earlier runs
+    count ties, later ones strictly, as in :func:`lemma1_counts`.  Exact
+    for any ``v`` with ``L <= i``: when ``i`` is past every element the
+    cut is the lengths.
+    """
+    eq = le - lt
+    ties_before = jnp.cumsum(eq, axis=0) - eq
+    return lt + jnp.clip(i - lt.sum(axis=0) - ties_before, 0, eq)
 
 
 # ---------------------------------------------------------------------------
@@ -368,6 +394,66 @@ def co_rank_search(
     if metric is not None and obs.enabled():
         obs.gauge(metric, rounds, bound=rounds, **(labels or {}))
     return lo
+
+
+def co_rank_value_search(
+    i,
+    count_le,
+    lengths,
+    key_range: tuple[int, int],
+    *,
+    metric: str | None = None,
+    labels: dict | None = None,
+):
+    """Cut vectors of ranks ``i`` by bisecting the merged order's *keys*.
+
+    Over int32 keys in ``key_range = (lo, hi)`` (every key, padding
+    included, inside it; ``hi - lo + 1`` a power of two): each rank ``i``
+    (shape ``(b,)``) looks for ``v`` = the smallest key with
+    ``sum_r min(count_le_r(v), len_r) > i``, the key of the merged
+    order's element ``i``; then :func:`tie_split_cuts` splits its ties.
+    One static round per bit of the range, each one ``count_le`` over all
+    ``b`` midpoints at once: ``k`` searches a round, where
+    :func:`co_rank_search` needs a merged rank (``k`` searches) per run.
+
+    The loop carries both sides of the tie split: the ``<= hi`` counts,
+    and the ``<= lo - 1`` counts, which over integers are the ``< lo``
+    counts.  The range halves exactly every round, so at the end
+    ``lo == hi == v`` and the split needs no search of its own.
+
+    ``count_le(v)`` returns each run's raw count of elements ``<= v``
+    (``SIDE_TIES``) as ``(k, b)``; ``lengths`` is ``(k, 1)``.  Returns
+    ``(k, b)`` cuts with ``j.sum(0) == clip(i, 0, total)``.
+    """
+    lo0, hi0 = key_range
+    rounds = (hi0 - lo0).bit_length()
+
+    def body(state):
+        lo, hi, le, lt = state
+        # floor((lo + hi) / 2) without leaving int32.
+        mid = (lo >> 1) + (hi >> 1) + (lo & hi & 1)
+        le_mid = jnp.minimum(count_le(mid), lengths)
+        past = le_mid.sum(axis=0) > i
+        return (
+            jnp.where(past, lo, mid + 1),
+            jnp.where(past, mid, hi),
+            jnp.where(past, le_mid, le),
+            jnp.where(past, lt, le_mid),
+        )
+
+    shape = (lengths.shape[0], *i.shape)
+    state = (
+        jnp.full(i.shape, lo0, jnp.int32),
+        # ``hi`` has every element at or below it: past the last rank it
+        # stays the top of the range, where the cut is the lengths.
+        jnp.full(i.shape, hi0, jnp.int32),
+        jnp.broadcast_to(lengths, shape),
+        jnp.zeros(shape, jnp.int32),  # nothing lies below the range
+    )
+    _, _, le, lt = run_fori(rounds, body, state)
+    if metric is not None and obs.enabled():
+        obs.gauge(metric, rounds, bound=rounds, **(labels or {}))
+    return tie_split_cuts(i, le, lt)
 
 
 # ---------------------------------------------------------------------------

@@ -20,11 +20,15 @@ every other run.  ``rank(r, ·)`` is strictly increasing, so the cut
 
     j_r(i) = |{t : rank(r, t) < i}|
 
-is found by one binary search per run whose predicate evaluates the
-k-way Lemma-1 conditions (``ceil(log2 w)+1`` rounds, each round ``k``
-``searchsorted`` probes — all runs search in lock-step, vectorised).
-``sum_r j_r(i) == i`` holds exactly because ``rank`` is a bijection onto
-``0..k*w-1``.
+is found, over the keys mapped to int32 in the same order, by bisecting
+the *key* of the merged order's ``i``-th element (one round per key bit,
+each ``k`` searches, one per run), then splitting that key's ties across
+the runs in run order (``engine.co_rank_value_search``).  ``sum_r
+j_r(i) == i`` holds exactly because ``rank`` is a bijection onto
+``0..k*w-1``.  Keys of at most 32 bits are taken: integers widen
+(uint32 flips its top bit), floats widen to float32 and map to int32 by
+the radix-sort key map, ``-0.0`` tied with ``+0.0`` as ``<`` ties them
+(NaN has no place in the order, as in the merge kernels).
 
 On top of the cut sit two merges:
 
@@ -64,56 +68,62 @@ __all__ = [
 ]
 
 
-class _DenseProbe:
-    """Engine probe over an on-device ``(k, w)`` run array.
+_INT32_RANGE = (-(2**31), 2**31 - 1)
 
-    ``values`` is one clamped gather; ``counts`` is a vmapped
-    ``searchsorted`` per Lemma-1 side; the loop lowers as a static
-    ``lax.fori_loop`` (jit/vmap-safe).  See ``repro.core.engine`` for
-    the protocol.
+
+def _int32_keys(runs: jax.Array) -> tuple[jax.Array, tuple[int, int]]:
+    """Keys as int32 in the same order, and the range they lie in.
+
+    Integers of at most 32 bits widen (uint32 flips its top bit).
+    Floats of at most 32 bits widen to float32; ``-0.0`` becomes
+    ``+0.0``, then the bits of negatives flip below the sign, which
+    orders the int32 patterns as the floats.
     """
+    dtype = runs.dtype
+    if dtype.itemsize <= 4 and jnp.issubdtype(dtype, jnp.floating):
+        x = runs.astype(jnp.float32)
+        b = lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.int32)
+        return b ^ ((b >> 31) & jnp.int32(2**31 - 1)), _INT32_RANGE
+    if dtype.itemsize <= 4 and jnp.issubdtype(dtype, jnp.integer):
+        if dtype == jnp.uint32:
+            flipped = runs ^ jnp.uint32(2**31)
+            return lax.bitcast_convert_type(flipped, jnp.int32), _INT32_RANGE
+        info = jnp.iinfo(dtype)
+        return runs.astype(jnp.int32), (int(info.min), int(info.max))
+    raise TypeError(f"k-way co-rank takes keys of at most 32 bits, not {dtype}")
 
-    xp = jnp
-    run_loop = staticmethod(engine.run_fori)
 
-    def __init__(self, runs: jax.Array, lengths: jax.Array):
-        k, w = runs.shape
-        self.runs = runs
-        self.width = w
-        self.lengths = lengths  # (k,)
-        self.owner_ids = jnp.arange(k, dtype=jnp.int32)[:, None]
-        self.query_ids = jnp.arange(k, dtype=jnp.int32)[None, :]
-        self.owner_lengths = lengths[:, None]
-        self._rows = jnp.arange(k, dtype=jnp.int32)
+def _row_counts(rows: jax.Array, v: jax.Array) -> jax.Array:
+    """``searchsorted(rows[r], v, SIDE_TIES)`` for every row: ``(k, w)``
+    rows and ``(b,)`` keys -> ``(k, b)`` int32 counts.
 
-    def init_bounds(self, i):
-        k = self.runs.shape[0]
-        return jnp.zeros((k,), jnp.int32), self.lengths
+    All ``k * b`` binary searches step in lock-step over the flattened
+    rows, so each step is one plain gather.  ``jax.vmap`` of
+    ``jnp.searchsorted`` gathers with a batch dimension instead, which a
+    v5e ran 2-3x slower at k=16 and in a time that swung with the keys
+    (the value search of a 2^24-key merge's 4,097 tile boundaries: 335
+    ms against 709-998 ms).
+    """
+    k, w = rows.shape
+    flat = rows.reshape(-1)
+    base = (jnp.arange(k, dtype=jnp.int32) * w)[:, None]
 
-    def values(self, t):
-        return self.runs[self._rows, jnp.clip(t, 0, self.width - 1)]
+    def step(_, lo_hi):
+        # ``jnp.searchsorted``'s scan: ``mid < hi <= w`` always.
+        lo, hi = lo_hi
+        mid = (lo + hi) // 2
+        counted = engine.count_below(flat[base + mid], v, ties=True)
+        return jnp.where(counted, mid, lo), jnp.where(counted, hi, mid)
 
-    def counts(self, x):
-        le = jax.vmap(lambda row: jnp.searchsorted(row, x, side=SIDE_TIES))(
-            self.runs
-        ).astype(jnp.int32)
-        lt = jax.vmap(lambda row: jnp.searchsorted(row, x, side=SIDE_STRICT))(
-            self.runs
-        ).astype(jnp.int32)
-        return le, lt
-
-    def reduce(self, cnt):
-        return cnt.sum(axis=0)
+    shape = (k, *v.shape)
+    lo_hi = (jnp.zeros(shape, jnp.int32), jnp.full(shape, w, jnp.int32))
+    return lax.fori_loop(0, w.bit_length(), step, lo_hi)[1]
 
 
 def co_rank_kway(
     i: jax.Array, runs: jax.Array, lengths: jax.Array | None = None
 ) -> jax.Array:
     """Cut vector ``j`` (shape ``(k,)``) of output rank ``i`` into ``runs``.
-
-    The dense-array instantiation of ``engine.co_rank_search`` — the
-    lock-step k-way Lemma-1 bisection, ``kway_round_bound(w)`` rounds of
-    ``k`` vectorised ``searchsorted`` probes.
 
     Args:
       i: output rank, ``0 <= i <= sum(lengths)`` (scalar, may be traced).
@@ -126,25 +136,32 @@ def co_rank_kway(
       stable k-way merge of the runs restricted to ``runs[r][:j[r]]`` is
       exactly its first ``i`` elements.
     """
-    k, w = runs.shape
     i = jnp.asarray(i, jnp.int32)
-    if lengths is None:
-        lengths = jnp.full((k,), w, jnp.int32)
-    else:
-        lengths = jnp.asarray(lengths, jnp.int32)
-    return engine.co_rank_search(
-        i,
-        _DenseProbe(runs, lengths),
-        metric="kway.corank_rounds",
-        labels={"k": k, "w": w},
-    )
+    return co_rank_kway_batch(i[None], runs, lengths)[0]
 
 
 def co_rank_kway_batch(
     i: jax.Array, runs: jax.Array, lengths: jax.Array | None = None
 ) -> jax.Array:
-    """Vectorised cuts for ranks ``i`` of shape ``(b,)`` -> ``(b, k)``."""
-    return jax.vmap(co_rank_kway, in_axes=(0, None, None))(i, runs, lengths)
+    """Vectorised cuts for ranks ``i`` of shape ``(b,)`` -> ``(b, k)``.
+
+    All ``b`` ranks search together by key value
+    (``engine.co_rank_value_search``): one round per bit of the keys'
+    range, ``k`` searches a round.
+    """
+    k, w = runs.shape
+    if lengths is None:
+        lengths = jnp.full((k,), w, jnp.int32)
+    keys, key_range = _int32_keys(runs)
+    j = engine.co_rank_value_search(
+        jnp.asarray(i, jnp.int32),
+        partial(_row_counts, keys),
+        jnp.asarray(lengths, jnp.int32)[:, None],
+        key_range,
+        metric="kway.corank_rounds",
+        labels={"k": k, "w": w, "search": "value"},
+    )
+    return j.T
 
 
 #: Rows up to this width are searched by comparing every query with every

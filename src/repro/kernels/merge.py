@@ -306,9 +306,9 @@ def _merge_tiles(cb, sources, *, k, s, g, nblocks, interpret, semantics,
 
 
 #: Most output tiles a k-way merge takes by default.  Phase 1 co-ranks
-#: every tile boundary with ``k**2`` batched searches per bisection
-#: round, whose gathers a TPU pays dearly for: at tile 512 a 2^24-element
-#: k=16 merge spent most of its 87 s on a v5e there.
+#: every tile boundary with batched searches (``k`` a round), whose
+#: gathers a TPU pays dearly for: at tile 512 a 2^24-element k=16 merge
+#: spent most of its 87 s on a v5e there, when a round took ``k**2``.
 KWAY_MAX_TILES = 4096
 
 

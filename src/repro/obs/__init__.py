@@ -39,9 +39,11 @@ Metrics catalog — every record point woven through the hot paths:
                              ``bound = ceil(log2 min(m, n)) + 1`` (the
                              paper's bound; value <= bound always),
                              ``m``, ``n``.
-``kway.corank_rounds``       gauge: lock-step binary-search rounds of
-                             the k-way cut (static ``ceil(log2 w)+1``);
-                             labels ``bound``, ``k``, ``w``.
+``kway.corank_rounds``       gauge: lock-step bisection rounds of the
+                             k-way cut, one per bit of the keys' range
+                             (32 for int32 and floats, 8 for int8);
+                             labels ``bound``, ``k``, ``w``, ``search``
+                             (``"value"``: the key bisection).
 ``splitters.kway_rounds``    gauge: collective rounds of the
                              distributed k-way splitter; labels
                              ``bound``, ``w``, ``device``.

@@ -12,7 +12,13 @@ former transcriptions used to drift:
 * ±inf floats (comparison edge values);
 * real int32 dtype-max elements coexisting with dtype-max padding;
 * pre-sorted inputs (degenerate cuts: whole runs taken in order);
-* ragged / zero-length runs behind the ``lengths`` sideband.
+* ragged / zero-length runs behind the ``lengths`` sideband;
+* the value search of ``core.kway``, over keys mapped to int32: real
+  int32 keys at both ends of the range next to int32-max padding
+  (midpoint overflow, the length clamp), uint32 keys above 2^31, int8
+  and int16 keys, one key shared by every run with the swept ranks
+  inside its ties, float32 signed zeros (tied, as ``<`` ties them)
+  among negatives and infinities on ragged rows, and float16 keys.
 
 The oracle is engine-independent: a numpy stable ``lexsort`` over
 ``(value, run, offset)`` — the paper's definition of the stable k-way
@@ -104,6 +110,69 @@ def kway_cases(k: int):
         ("ragged_zero", _pad_rows(rows, w, np.int32(np.iinfo(np.int32).max)),
          np.asarray(lens, np.int32))
     )
+
+    # Real keys at both ends of int32, next to int32-max padding.
+    w = 20
+    edges = np.array([imax - 1, imax, -imax - 1, -imax, -1, 0, 1], np.int64)
+    rows = [np.sort(rng.choice(edges, int(rng.integers(0, w + 1))))
+            .astype(np.int32) for _ in range(k)]
+    cases.append(
+        ("int32_extremes", _pad_rows(rows, w, np.int32(imax)),
+         np.asarray([len(r) for r in rows], np.int32))
+    )
+
+    # uint32 keys above 2^31 (and a few below), uint32-max padding.
+    w = 20
+    umax = np.iinfo(np.uint32).max
+    edges = np.array(
+        [0, 5, 2**31 - 1, 2**31, 2**31 + 1, umax - 1, umax], np.uint64
+    )
+    rows = [np.sort(rng.choice(edges, int(rng.integers(1, w + 1))))
+            .astype(np.uint32) for _ in range(k)]
+    cases.append(
+        ("uint32_high", _pad_rows(rows, w, np.uint32(umax)),
+         np.asarray([len(r) for r in rows], np.int32))
+    )
+
+    # Narrow integers, widened to int32 by the value search.
+    for dtype, lo, hi in ((np.int8, -128, 128), (np.int16, -40, 40)):
+        w = 24
+        top = np.iinfo(dtype).max
+        rows = [np.sort(rng.integers(lo, hi, int(rng.integers(1, w + 1))))
+                .astype(dtype) for _ in range(k)]
+        cases.append(
+            (np.dtype(dtype).name, _pad_rows(rows, w, dtype(top)),
+             np.asarray([len(r) for r in rows], np.int32))
+        )
+
+    # One key (7) in every run, most of each run: the swept ranks fall
+    # inside its ties, which the cut splits across the runs in run order.
+    w = 32
+    runs = np.full((k, w), 7, np.int32)
+    for r in range(k):
+        below, above = rng.integers(0, 5, 2)
+        runs[r, :below] = np.sort(rng.integers(-5, 7, below))
+        runs[r, w - above :] = np.sort(rng.integers(8, 20, above))
+    cases.append(("shared_tie", runs, np.full(k, w, np.int32)))
+
+    # Float keys: -0.0 and +0.0 mixed in one tie, negatives, infinities
+    # real and as padding, ragged rows.
+    w = 24
+    pool = np.array(
+        [-np.inf, -2.5, -1.0, -0.0, 0.0, 0.5, 1.0, np.inf], np.float32
+    )
+    rows = [np.sort(rng.choice(pool, int(rng.integers(1, w + 1))))
+            for _ in range(k)]
+    cases.append(
+        ("signed_zero", _pad_rows(rows, w, np.float32(np.inf)),
+         np.asarray([len(r) for r in rows], np.int32))
+    )
+
+    # float16 keys, widened to float32 before the map.
+    w = 24
+    pool = np.array([-65504.0, -3.5, -0.0, 0.0, 0.25, 2.0, 65504.0])
+    runs = np.sort(rng.choice(pool, (k, w)), axis=1).astype(np.float16)
+    cases.append(("float16", runs, np.full(k, w, np.int32)))
 
     return cases
 

@@ -49,10 +49,16 @@ def rand_runs(rng, k, w, lo=0, hi=10, dtype=np.int32):
     return np.sort(rng.integers(lo, hi, (k, w)), axis=1).astype(dtype)
 
 
+#: Key dtypes of the co-rank tests: small integers are exact in float32,
+#: whose keys the co-rank maps to int32 before its search.
+KEY_DTYPES = pytest.mark.parametrize("dtype", [np.int32, np.float32])
+
+
+@KEY_DTYPES
 @pytest.mark.parametrize("k,w", [(2, 8), (3, 5), (4, 16), (8, 7), (16, 3)])
-def test_co_rank_kway_matches_oracle(k, w):
+def test_co_rank_kway_matches_oracle(k, w, dtype):
     rng = np.random.default_rng(k * 100 + w)
-    runs = rand_runs(rng, k, w)
+    runs = rand_runs(rng, k, w, dtype=dtype)
     ranks = jnp.arange(k * w + 1, dtype=jnp.int32)
     cuts = np.asarray(co_rank_kway_batch(ranks, jnp.asarray(runs)))
     for i in range(k * w + 1):
@@ -62,18 +68,20 @@ def test_co_rank_kway_matches_oracle(k, w):
         assert pairwise_lemma_holds(runs, j), (k, w, i, j)
 
 
-def test_co_rank_kway_cut_sum_invariant():
+@KEY_DTYPES
+def test_co_rank_kway_cut_sum_invariant(dtype):
     """sum(j_r) == i for every rank, heavy-duplicate input."""
     rng = np.random.default_rng(0)
-    runs = rand_runs(rng, 8, 32, lo=0, hi=3)  # massive duplication
+    runs = rand_runs(rng, 8, 32, lo=0, hi=3, dtype=dtype)  # duplicates
     ranks = jnp.arange(8 * 32 + 1, dtype=jnp.int32)
     cuts = np.asarray(co_rank_kway_batch(ranks, jnp.asarray(runs)))
     np.testing.assert_array_equal(cuts.sum(axis=1), np.asarray(ranks))
 
 
-def test_co_rank_kway_all_equal_stability():
+@KEY_DTYPES
+def test_co_rank_kway_all_equal_stability(dtype):
     """All-equal keys: cuts must drain runs strictly in run order."""
-    runs = jnp.zeros((4, 8), jnp.int32)
+    runs = jnp.zeros((4, 8), dtype)
     cuts = np.asarray(
         co_rank_kway_batch(jnp.arange(33, dtype=jnp.int32), runs)
     )
@@ -82,11 +90,12 @@ def test_co_rank_kway_all_equal_stability():
         np.testing.assert_array_equal(cuts[i], want)
 
 
-def test_co_rank_kway_ragged_lengths():
+@KEY_DTYPES
+def test_co_rank_kway_ragged_lengths(dtype):
     rng = np.random.default_rng(5)
     k, w = 4, 10
     lengths = np.array([10, 3, 7, 1], np.int32)
-    runs = np.full((k, w), np.iinfo(np.int32).max, np.int32)
+    runs = np.full((k, w), np.iinfo(np.int32).max, dtype)
     for r in range(k):
         runs[r, : lengths[r]] = np.sort(rng.integers(0, 6, lengths[r]))
     total = int(lengths.sum())
@@ -291,11 +300,40 @@ def test_prop1_runtime_iteration_counters():
             )
 
 
+def test_kway_corank_search_by_dtype():
+    """Phase 1 of the k-way merge runs the value search for every key
+    dtype, one round per bit of the dtype's range: 32 for int32 and for
+    floats (mapped to int32), 8 for int8."""
+    from repro import obs
+
+    rng = np.random.default_rng(41)
+    k, w = 4, 32
+    runs = np.sort(rng.integers(0, 5, (k, w)), axis=1)
+    bounds = {np.int32: 32, np.float32: 32, np.int8: 8}
+    with obs.capture() as recs:
+        for dtype in bounds:
+            ids = np.arange(k * w, dtype=np.int32).reshape(k, w)
+            keys, vals = merge_kway_pallas(
+                jnp.asarray(runs.astype(dtype)),
+                jnp.asarray(ids),
+                tile=16,
+                interpret=True,
+            )
+            order = np.argsort(runs.ravel(), kind="stable")
+            np.testing.assert_array_equal(np.asarray(vals), ids.ravel()[order])
+        obs.flush()
+    rounds = [r for r in recs if r["metric"] == "kway.corank_rounds"]
+    assert [r["labels"]["search"] for r in rounds] == ["value"] * 3
+    assert [r["labels"]["bound"] for r in rounds] == list(bounds.values())
+    assert [r["value"] for r in rounds] == list(bounds.values())
+
+
 # ---------------------------------------------------------------------------
 # properties (hypothesis when installed, seeded fallback offline)
 # ---------------------------------------------------------------------------
 
 
+@KEY_DTYPES
 @settings(max_examples=50, deadline=None)
 @given(
     st.integers(2, 8),
@@ -303,9 +341,9 @@ def test_prop1_runtime_iteration_counters():
     st.integers(0, 2**31 - 1),
     st.data(),
 )
-def test_co_rank_kway_property(k, w, seed, data):
+def test_co_rank_kway_property(dtype, k, w, seed, data):
     rng = np.random.default_rng(seed)
-    runs = rand_runs(rng, k, w, lo=-9, hi=9)
+    runs = rand_runs(rng, k, w, lo=-9, hi=9, dtype=dtype)
     i = data.draw(st.integers(0, k * w))
     j = np.asarray(co_rank_kway(i, jnp.asarray(runs)))
     np.testing.assert_array_equal(j, oracle_cuts(runs, i))
